@@ -31,7 +31,7 @@ from repro.core import (ExecutionSpec, VariantCache, build_acorn_gamma,
                         recall_at_k, search_batch)
 from repro.data import make_lcps_dataset, make_workload
 
-from .common import timed_qps
+from .common import interpret_kernels, timed_qps
 
 BATCH_SIZES = (1, 16, 64, 256)
 M, GAMMA, MBETA = 8, 8, 16
@@ -52,7 +52,8 @@ def _make_runner(graph, x, xq, masks, bs: int, nq: int, use_kernel: bool):
                 graph, x, xq[s:s + bs], masks[s:s + bs], k=K, ef=EF,
                 variant="acorn-gamma", m=M, m_beta=MBETA,
                 compressed_level0=False,
-                spec=ExecutionSpec(use_kernel=use_kernel, interpret=True),
+                spec=ExecutionSpec(use_kernel=use_kernel,
+                                   interpret=interpret_kernels()),
                 buckets=(bs,), cache=cache)
             outs.append(ids)
         return jnp.concatenate(outs)

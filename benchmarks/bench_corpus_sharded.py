@@ -8,7 +8,9 @@ and writes ``BENCH_corpus_sharded.json`` at the repo root.  XLA fixes the
 host device count at first init, so the sweep runs in ONE child process
 launched with ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` —
 every mesh shape is a reshape of the same 8 virtual devices (exactly the
-"scaling the corpus is a mesh-shape change" claim).
+"scaling the corpus is a mesh-shape change" claim).  That child is a CPU
+rehearsal only: on a TPU host the sweep stops with an error
+(``benchmarks.common.virtual_cpu_env``).
 
 Claims validated:
   * the SPMD path is bit-identical to the host loop at every mesh shape
@@ -107,11 +109,8 @@ def _child(args) -> None:
 
 
 def _sweep(shapes, batches, n):
-    ndev = max(dp * cp for dp, cp in shapes)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
-    env["PYTHONPATH"] = "src"
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    from benchmarks.common import virtual_cpu_env
+    env = virtual_cpu_env(max(dp * cp for dp, cp in shapes))
     cmd = [sys.executable, "-m", "benchmarks.bench_corpus_sharded",
            "--child", "--n", str(n),
            "--batches", ",".join(str(b) for b in batches),

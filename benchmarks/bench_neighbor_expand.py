@@ -39,6 +39,8 @@ from repro.kernels.neighbor_expand import (neighbor_expand,
                                            neighbor_expand_argsort,
                                            neighbor_expand_ref)
 
+from .common import interpret_kernels
+
 N_NODES = 8192
 B = 16
 M = 16
@@ -92,7 +94,7 @@ def _runner(impl: str, args, strategy: str, m_beta: int):
     if impl == "fused":
         return lambda: neighbor_expand_ref(row, tbl, pos, pm, vis, **kw)
     return lambda: neighbor_expand(row, tbl, pos, pm, vis, use_kernel=True,
-                                   interpret=True, **kw)
+                                   interpret=interpret_kernels(), **kw)
 
 
 def _points(quick: bool):

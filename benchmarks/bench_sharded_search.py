@@ -6,7 +6,9 @@ Measures QPS of the query-data-parallel ``search_batch`` dispatch
 at the repo root.  XLA fixes the host device count at first init, so every
 sweep point runs in a child process launched with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=<devices>`` (the same
-recipe the distributed tests use).
+recipe the distributed tests use).  Those children are a CPU rehearsal
+only: on a TPU host the sweep stops with an error
+(``benchmarks.common.virtual_cpu_env``).
 
 Claims validated:
   * sharding pays even on a small host: 4-device QPS > 1-device QPS at
@@ -70,7 +72,7 @@ def _child(args) -> None:
         cache = VariantCache()
         kw = dict(k=K, ef=EF, variant="acorn-gamma", m=M, m_beta=MBETA,
                   compressed_level0=False,
-                  spec=ExecutionSpec(use_kernel=False, interpret=True,
+                  spec=ExecutionSpec(use_kernel=False,
                                      data_parallel=dp),
                   buckets=(bs,), cache=cache)
 
@@ -96,12 +98,10 @@ def _child(args) -> None:
 
 def _sweep(device_counts, batches, n):
     """Run one child per device count; collect its results + parity digest."""
+    from benchmarks.common import virtual_cpu_env
     out = []
     for dp in device_counts:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={dp}"
-        env["PYTHONPATH"] = "src"
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env = virtual_cpu_env(dp)
         cmd = [sys.executable, "-m", "benchmarks.bench_sharded_search",
                "--child", "--devices", str(dp),
                "--batches", ",".join(str(b) for b in batches),

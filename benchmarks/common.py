@@ -34,6 +34,38 @@ K = 10
 EF_SWEEP = (16, 32, 64, 128)
 
 
+def interpret_kernels() -> bool:
+    """Whether Pallas kernels must run in interpret mode here: only on the
+    CPU, which has no Mosaic backend (a CPU number then measures the
+    interpreter, not the kernel).  On a TPU they compile."""
+    return jax.devices()[0].platform == "cpu"
+
+
+def virtual_cpu_env(n_devices: int) -> Dict[str, str]:
+    """Environment for a child process that rehearses an ``n_devices``
+    mesh on virtual CPU devices.
+
+    Only a CPU host starts such children.  On an accelerator this process,
+    having touched JAX, holds the chip: a child would either fight it for
+    the chip or quietly measure the CPU.  There the sweep stops with an
+    error instead (``python chip_smoke.py --four-chips`` drives the
+    corpus-sharded path on a four-chip host, in one process)."""
+    platform = jax.devices()[0].platform
+    if platform != "cpu":
+        raise RuntimeError(
+            f"this sweep rehearses meshes on virtual CPU devices in child "
+            f"processes and cannot run on a {platform} host, where this "
+            "process holds the chip; run it with JAX_PLATFORMS=cpu, or "
+            "drive the chip with chip_smoke.py")
+    env = dict(os.environ)
+    flags = env.get("XLA_FLAGS", "")
+    env["XLA_FLAGS"] = (
+        f"{flags} --xla_force_host_platform_device_count={n_devices}".strip())
+    env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
 def out_path(name: str) -> str:
     os.makedirs(BENCH_DIR, exist_ok=True)
     return os.path.join(BENCH_DIR, name)
@@ -66,12 +98,13 @@ def write_csv(name: str, header: List[str], rows: List[List]) -> str:
 
 def run_acorn(graph, x, wl, ds, ef: int, variant: str, m: int, m_beta: int,
               compressed: bool = True, use_kernel: bool = False,
-              interpret: bool = True) -> Dict:
+) -> Dict:
     masks, gt = wl.masks(ds), wl.gt(ds)
     kw = dict(k=K, ef=ef, variant=variant, m=m, m_beta=m_beta,
               compressed_level0=compressed and variant == "acorn-gamma",
               max_expansions=4 * ef,
-              spec=ExecutionSpec(use_kernel=use_kernel, interpret=interpret))
+              spec=ExecutionSpec(use_kernel=use_kernel,
+                                 interpret=interpret_kernels()))
     ids, _, st = hybrid_search(graph, x, wl.xq, masks, **kw)
     qps = timed_qps(lambda: hybrid_search(graph, x, wl.xq, masks, **kw)[0],
                     wl.xq.shape[0])

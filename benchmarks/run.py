@@ -55,6 +55,8 @@ def main() -> None:
                     help="tiny-N CI gate; nonzero exit on recall collapse")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    from repro.compile_cache import configure_compile_cache
+    configure_compile_cache()
     if args.smoke:
         sys.exit(smoke())
     only = set(args.only.split(",")) if args.only else None

@@ -4,6 +4,7 @@ query-plan API: SearchRequest in, compiled predicate program underneath.
 
   PYTHONPATH=src python examples/quickstart.py
 """
+import jax
 import numpy as np
 
 from repro.core import (AcornConfig, Between, ContainsAny, ExecutionSpec,
@@ -31,9 +32,11 @@ ids, dists, info = index.search(request)
 print(f"recall@10 = {recall_at_k(ids, wl.gt(ds)):.3f} | routes: "
       f"{dict(zip(*np.unique(info['routes'], return_counts=True)))}")
 
-# 3b. execution policy is one value — e.g. flip the Pallas kernels on:
+# 3b. execution policy is one value — e.g. flip the Pallas kernels on
+#     (compiled on a TPU; the CPU can only interpret them):
+on_cpu = jax.devices()[0].platform == "cpu"
 ids_k, _, _ = index.search(request, spec=ExecutionSpec(use_kernel=True,
-                                                       interpret=True))
+                                                       interpret=on_cpu))
 print("kernel path identical ids:",
       bool((np.asarray(ids) == np.asarray(ids_k)).all()))
 

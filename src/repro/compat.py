@@ -1,22 +1,14 @@
-"""JAX version-compatibility shims.
+"""JAX API shims.
 
-``jax.shard_map`` graduated from ``jax.experimental.shard_map`` (and its
-``check_rep`` knob was renamed ``check_vma``) in newer JAX releases; the
-pinned CI environment (jax 0.4.x) only has the experimental spelling.
+``shard_map`` keeps one call signature across the repo: ``check_vma``
+is passed only when the caller sets it.
 """
 from __future__ import annotations
 
 import jax
 
-try:  # jax >= 0.6: public API, check_vma knob
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-except AttributeError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    kw = {} if check_vma is None else {_CHECK_KW: check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+    kw = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)

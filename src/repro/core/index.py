@@ -53,7 +53,7 @@ class AcornConfig:
     # execution knobs (batched kernel-fused pipeline); bundled on demand
     # into an ExecutionSpec by .execution_spec()
     use_kernel: bool = False           # gather_distance Pallas kernel
-    interpret: bool = True             # interpret=True runs the kernel on CPU
+    interpret: bool = False            # interpret=True runs the kernel on CPU
     # neighbor_expand Pallas kernel (fused 2-hop gather/filter/dedup/pack);
     # None follows use_kernel
     expand_kernel: Optional[bool] = None

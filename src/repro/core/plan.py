@@ -67,7 +67,8 @@ class ExecutionSpec:
 
     ``use_kernel``      — route distances through the gather_distance
                           Pallas kernel (pure-jnp reference otherwise);
-    ``interpret``       — run Pallas kernels in interpret mode (CPU CI);
+    ``interpret``       — run Pallas kernels in interpret mode (the CPU
+                          has no Mosaic backend; tests ask for it);
     ``expand_kernel``   — route neighbor expansion through its Pallas
                           kernel; ``None`` follows ``use_kernel``;
     ``data_parallel``   — query-shard the batch over this many local
@@ -80,7 +81,7 @@ class ExecutionSpec:
     """
 
     use_kernel: bool = False
-    interpret: bool = True
+    interpret: bool = False
     expand_kernel: Optional[bool] = None
     data_parallel: Optional[int] = 1
     corpus_parallel: Optional[int] = None

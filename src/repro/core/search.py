@@ -9,16 +9,17 @@ every lane's condition is false, and a converged lane's carry is frozen.
 Batching the loop state (rather than ``vmap``-ing a scalar search) lets
 every beam-expansion distance computation issue as ONE call over the whole
 query batch, which routes through the ``gather_distance`` Pallas kernel
-(DMA-gathered rows + fused distance) when ``use_kernel=True`` — on CPU CI
-the kernel runs in interpret mode (``interpret=True``); ``use_kernel=False``
-selects the pure-jnp reference path.  The per-expansion beam update is a
+(DMA-gathered rows + fused distance) when ``use_kernel=True`` — on the
+CPU the kernel runs only in interpret mode (``interpret=True``, asked for
+explicitly); ``use_kernel=False`` selects the pure-jnp reference path.  The per-expansion beam update is a
 bounded sorted-merge (``repro.kernels.filtered_topk.bounded_sorted_merge``)
 instead of a full ``argsort`` of the (ef + M) concatenation: the beam is
 already sorted, so only the M candidates need ordering.
 
 Neighbor-lookup strategies (Figure 4):
-  'plain'    — first entries of N^l(c), no predicate (HNSW search +
-               construction-time metadata-agnostic lookups).
+  'plain'    — first entries of N^l(c), no predicate (HNSW search,
+               construction-time metadata-agnostic lookups, and every
+               variant's upper-level descent — see ``_search_impl``).
   'filter'   — scan N^l(c), keep predicate-passing, truncate to M (ACORN-γ,
                uncompressed levels — Fig 4a).
   'compress' — first M_β entries filtered directly; remaining entries
@@ -47,6 +48,8 @@ import jax.numpy as jnp
 from repro.kernels.filtered_topk.merge import bounded_sorted_merge
 from repro.kernels.gather_distance.ops import gather_distance
 from repro.kernels.gather_distance.ref import gather_distance_ref
+from repro.kernels.neighbor_expand.kernel import (neighbor_expand_packed,
+                                                  pack_bitmap)
 from repro.kernels.neighbor_expand.ops import neighbor_expand
 
 from .graph import INVALID, LayeredGraph, neighbor_rows
@@ -107,7 +110,7 @@ def get_neighbors(
     m_beta: int,
     visited: Optional[Array] = None,
     use_kernel: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> Array:
     """Return up to ``m`` neighbor ids of node ``c`` for the query predicate.
 
@@ -151,7 +154,7 @@ def _strategy_for(variant: str, level: int, compressed_level0: bool) -> str:
 
 
 def _batched_neighbors(graph, level, cs, pass_mask, strategy, m, m_beta,
-                       visited=None, use_kernel=False, interpret=True):
+                       visited=None, use_kernel=False, interpret=False):
     """get_neighbors over the query batch: (B,) ids -> (B, M).
 
     Natively batched (no vmap): the whole batch's expansions issue as one
@@ -185,10 +188,10 @@ def _batch_dists(x: Array, ids: Array, xq: Array, metric: str,
     return gather_distance_ref(ids, xq, x, metric)
 
 
-def _greedy_level(graph, x, level, e, ed, xq, pass_mask, strategy, m,
-                  m_beta, metric, max_steps, dc, use_kernel, interpret,
-                  expand_kernel):
-    """Batched ef=1 greedy descent at one level (Algorithm 1 upper levels).
+def _greedy_level(graph, x, level, e, ed, xq, metric, max_steps, dc,
+                  use_kernel, interpret):
+    """Batched ef=1 greedy descent at one level (Algorithm 1 upper levels)
+    over the full neighbor lists, with no predicate.
 
     e (B,) current nodes, ed (B,) their distances; lanes freeze once their
     own step stops improving (vmap-of-while_loop carry contract)."""
@@ -203,9 +206,7 @@ def _greedy_level(graph, x, level, e, ed, xq, pass_mask, strategy, m,
     def body(state):
         e, ed, moved, it, dc = state
         active = lane_cond(state)
-        nbrs = _batched_neighbors(graph, level, e, pass_mask, strategy, m,
-                                  m_beta, use_kernel=expand_kernel,
-                                  interpret=interpret)
+        nbrs = neighbor_rows(graph, level, e)
         d = _batch_dists(x, nbrs, xq, metric, use_kernel, interpret)
         dc2 = dc + jnp.sum(nbrs >= 0, axis=1, dtype=jnp.int32)
         j = jnp.argmin(d, axis=1)
@@ -251,16 +252,26 @@ def _search_impl(
     n = x.shape[0]
     top = graph.num_levels - 1
     rows = jnp.arange(b)
+    # the expansion kernel reads the predicate as a packed bitmap: pack it
+    # once per batch, not once per expansion
+    kmask = (pack_bitmap(pass_mask)
+             if expand_kernel and pass_mask is not None else pass_mask)
     e = jnp.broadcast_to(graph.entry_point, (b,)).astype(jnp.int32)
     ed = _batch_dists(x, e[:, None], xq, metric, use_kernel, interpret)[:, 0]
     dc = jnp.ones((b,), jnp.int32)
 
     # ---- stage 1 + upper levels: greedy descent (Algorithm 1) ----
+    # Predicate-agnostic (beyond-paper: the paper walks the predicate
+    # subgraph here).  The descent only picks the level-0 entry, and a
+    # filtered one lands on the nearest *passing* upper-level node: when
+    # the query's region holds none (a sparse upper level, a selective or
+    # clustered predicate), it lands in another region whose level-0
+    # lists never reach the query's, and recall plateaus whatever ef is.
+    # Walking the full upper-level lists lands next to the query; the
+    # level-0 beam and the seeds below apply the predicate.
     for lvl in range(top, 0, -1):
-        strat = _strategy_for(variant, lvl, compressed_level0)
-        e, ed, dc = _greedy_level(graph, x, lvl, e, ed, xq, pass_mask, strat,
-                                  m, m_beta, metric, 128, dc, use_kernel,
-                                  interpret, expand_kernel)
+        e, ed, dc = _greedy_level(graph, x, lvl, e, ed, xq, metric, 128, dc,
+                                  use_kernel, interpret)
 
     # ---- level 0: beam search (Algorithm 2) ----
     strat0 = _strategy_for(variant, 0, compressed_level0)
@@ -284,7 +295,7 @@ def _search_impl(
     # step already paid in spirit; ef must simply be > m).
     if pass_mask is not None and graph.num_levels > 1 and ef > m:
         strat1 = _strategy_for(variant, 1, compressed_level0)
-        seeds = _batched_neighbors(graph, 1, e, pass_mask, strat1, m, m_beta,
+        seeds = _batched_neighbors(graph, 1, e, kmask, strat1, m, m_beta,
                                    use_kernel=expand_kernel,
                                    interpret=interpret)
         seeds = seeds[:, :m]  # 'plain' rows may be wider than m
@@ -326,16 +337,26 @@ def _search_impl(
         c = jnp.take_along_axis(beam_ids, sel[:, None], axis=1)[:, 0]
         beam_exp2 = beam_exp.at[rows, sel].set(True)
 
-        nbrs = _batched_neighbors(graph, 0, c, pass_mask, strat0, m, m_beta,
-                                  visited=visited, use_kernel=expand_kernel,
-                                  interpret=interpret)
-        safe = jnp.clip(nbrs, 0, n - 1)
-        fresh = (nbrs >= 0) & ~jnp.take_along_axis(visited, safe, axis=1)
+        if packed_visited:
+            # the kernel skips visited ids and sets the bits of those it
+            # returns, in place: every returned id is fresh
+            nbrs, visited2 = neighbor_expand_packed(
+                neighbor_rows(graph, 0, c), graph.neighbors[0],
+                graph.pos[0], kmask, visited, strategy=strat0, m=m,
+                m_beta=m_beta, interpret=interpret)
+            fresh = nbrs >= 0
+        else:
+            nbrs = _batched_neighbors(graph, 0, c, pass_mask, strat0, m,
+                                      m_beta, visited=visited,
+                                      use_kernel=expand_kernel,
+                                      interpret=interpret)
+            safe = jnp.clip(nbrs, 0, n - 1)
+            fresh = (nbrs >= 0) & ~jnp.take_along_axis(visited, safe, axis=1)
+            visited2 = visited.at[rows[:, None], safe].max(nbrs >= 0)
         nd = jnp.where(fresh,
                        _batch_dists(x, nbrs, xq, metric, use_kernel,
                                     interpret), INF)
         dc2 = dc + jnp.sum(fresh, axis=1, dtype=jnp.int32)
-        visited2 = visited.at[rows[:, None], safe].max(nbrs >= 0)
 
         # bounded sorted-merge into the beam: O((ef+M) log M), not a full
         # (ef+M) argsort — beam is sorted, only the M candidates are not
@@ -348,6 +369,9 @@ def _search_impl(
         return tuple(jnp.where(_lanes(active, nw.ndim), nw, od)
                      for nw, od in zip(new_state, state))
 
+    packed_visited = expand_kernel and strat0 != "plain"
+    if packed_visited:
+        visited = pack_bitmap(visited)
     state = (beam_ids, beam_d, beam_exp, beam_pass, visited,
              jnp.zeros((b,), jnp.int32), dc)
     beam_ids, beam_d, beam_exp, beam_pass, visited, hops, dc = (

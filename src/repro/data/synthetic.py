@@ -168,6 +168,8 @@ def make_workload(
     seed: int = 1,
     card: int = 12,
     date_width: int = 30,
+    range_column: str = "date",
+    value_range: int = 120,
 ) -> Workload:
     """Build a query workload over ``ds``.
 
@@ -175,6 +177,10 @@ def make_workload(
           'regex' (HCPS).
     correlation: 'none' | 'pos' | 'neg' — matches Figure 2 / §7.1.2. Only
           meaningful for 'contains' on clustered HCPS data.
+    'between' draws ``Between(range_column, lo, lo + date_width)`` with
+    ``lo`` uniform in ``[0, value_range - date_width)`` — HCPS dates by
+    default; ``range_column="label", value_range=card`` gives LCPS a wider
+    predicate than equality.
     """
     rng = np.random.default_rng(seed)
     n, d = ds.n, ds.d
@@ -191,8 +197,8 @@ def make_workload(
         for i in range(n_queries):
             qc = int(ds.cluster_of[qi[i]])
             if kind == "between":
-                lo = int(rng.integers(0, 120 - date_width))
-                preds.append(Between("date", lo, lo + date_width))
+                lo = int(rng.integers(0, value_range - date_width))
+                preds.append(Between(range_column, lo, lo + date_width))
                 continue
             if correlation == "pos":
                 kws = ds.cluster_keywords[qc]

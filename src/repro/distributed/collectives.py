@@ -76,10 +76,14 @@ def sharded_topk(mesh: Mesh, dp, tp: str = "model"):
     (score-descending, ties broken by smallest id).
 
     The merged result is replicated along ``tp``, but the out_specs emit
-    it under an explicit leading ``tp`` dim (sliced off outside) instead
-    of leaving the axis unmentioned: with the replication check off,
-    GSPMD's assembly of an unmentioned output axis is unspecified and can
-    compile to a cross-replica sum (see corpus_parallel.corpus_search_fn).
+    it under an explicit leading ``tp`` dim instead of leaving the axis
+    unmentioned: with the replication check off, GSPMD's assembly of an
+    unmentioned output axis is unspecified and can compile to a
+    cross-replica sum (see corpus_parallel.corpus_search_fn).  The copies
+    are identical, so a max over that dim returns exactly one of them.
+    Unlike indexing copy 0, the reduction is well-typed on the Explicit
+    meshes ``jax.make_mesh`` builds (where ``[0]`` on a sharded axis has no
+    output sharding) as well as on Auto meshes.
     """
 
     def make(k: int):
@@ -98,7 +102,7 @@ def sharded_topk(mesh: Mesh, dp, tp: str = "model"):
 
         def apply(scores, ids):
             mi, ms = f(scores, ids)
-            return mi[0], ms[0]
+            return mi.max(axis=0), ms.max(axis=0)
 
         return apply
 

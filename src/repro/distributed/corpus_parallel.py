@@ -67,7 +67,7 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.compat import shard_map
 from repro.core.batched import VariantCache, pad_rows, plan_chunks
@@ -244,6 +244,14 @@ def corpus_mesh(dp: int, cp: int) -> Mesh:
     return mesh
 
 
+def place_corpus(corpus: ShardedCorpus, dp: int, cp: int) -> ShardedCorpus:
+    """Put shard ``s`` of every corpus array on corpus device ``s`` of the
+    ``(dp, cp)`` mesh (replicated along ``data``) — the placement the SPMD
+    program's in_specs name, so a batch moves no corpus bytes."""
+    return jax.device_put(corpus,
+                          NamedSharding(corpus_mesh(dp, cp), P("corpus")))
+
+
 def resolve_corpus_mesh_shape(
     n_shards: int,
     data_parallel: Optional[int] = None,
@@ -259,9 +267,11 @@ def resolve_corpus_mesh_shape(
     ``corpus_parallel == n_shards`` explicitly to request SPMD even for a
     single shard (e.g. an 8x1 pure query-parallel mesh).  The data axis
     takes ``data_parallel`` clamped to the leftover device budget
-    (``None``/``0`` = all of it).  Returns ``None`` when the host cannot
-    fit the mesh — callers fall back to the host loop (availability
-    first).
+    (``None``/``0`` = all of it).  In auto mode, returns ``None`` when the
+    host cannot fit the mesh — callers serve through the host loop and
+    count it (``ServingEngine.stats["host_loop_batches"]``).  An explicit
+    ``corpus_parallel`` that the host cannot fit raises instead: a caller
+    that asked for the mesh never gets the host loop in its place.
     """
     auto = corpus_parallel in (None, 0)
     if not auto and int(corpus_parallel) != n_shards:
@@ -274,7 +284,11 @@ def resolve_corpus_mesh_shape(
     cp = n_shards
     ndev = local_device_count()
     if ndev < cp:
-        return None
+        if auto:
+            return None
+        raise ValueError(
+            f"corpus_parallel={cp} asks for one device per corpus shard "
+            f"but only {ndev} devices are local")
     budget = ndev // cp
     if not data_parallel:  # None / 0 -> all leftover devices
         dp = budget

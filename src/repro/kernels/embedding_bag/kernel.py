@@ -54,7 +54,7 @@ def _embedding_bag_kernel(ids_ref, table_ref, o_ref, row_ref, acc_ref, sems,
 
 @functools.partial(jax.jit, static_argnames=("mode", "interpret"))
 def embedding_bag_pallas(ids, table, mode: str = "sum",
-                         interpret: bool = True):
+                         interpret: bool = False):
     """ids (B, L) int32 (-1 padded), table (V, D) -> (B, D)."""
     b, l = ids.shape
     v, d = table.shape

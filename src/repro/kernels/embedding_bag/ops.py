@@ -43,7 +43,7 @@ _bag.defvjp(_bag_fwd, _bag_bwd)
 @functools.partial(jax.jit, static_argnames=("mode", "use_kernel",
                                              "interpret"))
 def embedding_bag(ids, table, mode: str = "sum", use_kernel: bool = True,
-                  interpret: bool = True):
+                  interpret: bool = False):
     """ids (B, L) int32 (-1 padded), table (V, D) -> (B, D)."""
     if not use_kernel:
         return embedding_bag_ref(ids, table, mode)

@@ -70,7 +70,7 @@ def _topk_block_kernel(q_ref, x_ref, mask_ref, scores_ref, ids_ref, *,
                    static_argnames=("k", "metric", "bq", "bc", "interpret"))
 def filtered_topk_pallas(q, x, mask, k: int, metric: str = "l2",
                          bq: int = 128, bc: int = 512,
-                         interpret: bool = True):
+                         interpret: bool = False):
     """(B, d) x (n, d) with (B, n) mask -> per-tile candidates.
 
     Returns (scores, ids): (B, n_blocks * k) tile-local top-k, to be reduced

@@ -13,7 +13,7 @@ from .ref import filtered_topk_ref
 @functools.partial(jax.jit, static_argnames=("k", "metric", "use_kernel",
                                              "interpret"))
 def filtered_topk(q, x, mask, k: int, metric: str = "l2",
-                  use_kernel: bool = True, interpret: bool = True):
+                  use_kernel: bool = True, interpret: bool = False):
     """Exact masked top-k over the corpus.
 
     q (B, d), x (n, d), mask (B, n) -> (ids (B, k) int32 [-1 padded],

@@ -2,29 +2,39 @@
 
 The per-hop candidate generation of ACORN's predicate-subgraph traversal
 (Figure 4b/4c): from the 1-hop neighbor row of the node being expanded,
-gather the 2-hop rows, drop predicate-failing / visited / duplicate ids,
+take the 2-hop rows, drop predicate-failing / visited / duplicate ids,
 and pack the first M survivors in candidate order.
 
-The jnp path materializes a ~(cap - m_beta) x (cap + 1) candidate array in
-HBM per lane and dedups it with a stable argsort (legacy) or a scatter-min
-first-occurrence pass (``ref.py``).  This kernel fuses all four steps: per
-lane it DMAs each needed 2-hop row from the HBM neighbor table straight
-into a VMEM tile (double-buffered, like ``gather_distance``) and runs one
-sequential first-occurrence scan over the candidate stream — a candidate
-packs iff it is valid, passes the predicate, is unvisited, and does not
-already sit in the (1, m) output tile (the packed set IS the dedup
-structure: once m ids are packed the scan is a no-op, so only packed ids
-can ever recur).  The flattened candidate array never exists in HBM, and
-nothing is sorted.
+The jnp path dedups the flattened ~(cap - m_beta) x (cap + 1) candidate
+array with a (B, n) scatter-min tile or a stable argsort (``ref.py``).
+This kernel fuses filter, dedup and pack into one sequential
+first-occurrence scan per lane: a candidate packs iff it is valid, passes
+the predicate and is not yet *seen* — where the seen set is the lane's
+visited bitmap plus every id packed so far (packing an id sets its bit),
+so the dedup costs one bit test and nothing is sorted.  The scan stops as
+soon as M ids are packed: later candidates could not change the output.
 
-Grid: one step per query lane.  1-hop ids and 2-hop row indices arrive via
-SMEM (they drive DMA addresses); the lane's predicate/visited bitmaps ride
-VMEM tiles indexed per candidate id — the 'onehot over node ids in VMEM'
-layout this kernel shares with the ref's scatter-min.
+Layout (what Mosaic accepts on a v5e, at any corpus size):
 
-CPU CI runs interpret mode only; the compiled lowering relies on scalar
-VMEM indexing, which Mosaic supports at reduced throughput — acceptable
-because the scan is DMA-latency-bound, not ALU-bound.
+  * every per-lane block carries a squeezed leading lane axis, so its last
+    two dims equal the array's (the (8, 128) block rule holds for any
+    width);
+  * the candidate ids — the 1-hop head, the tails to expand and their
+    2-hop rows — sit in SMEM, where the scalar scan reads them.  The 2-hop
+    rows are gathered by XLA before the call (one (B, t, cap) gather, the
+    same one the jnp path makes): a single row of a tiled (n_l, cap) table
+    cannot be DMA'd on its own unless cap is one 128-lane tile;
+  * the predicate and visited bitmaps are packed 32 ids per int32 word
+    into (n / 4096, 128) VMEM tiles per lane (:func:`pack_bitmap`) — n / 8
+    bytes, not the n bytes (padded to 32 sublanes) of a bool row — and a
+    bit test loads the word's 128-lane row and selects its lane.
+
+:func:`neighbor_expand_packed` takes both bitmaps already packed and
+returns the visited bitmap with the packed ids set, written in place over
+its input: the level-0 beam search packs its predicate once per batch and
+carries visited packed, so no hop packs a (B, n) mask.
+
+Grid: one step per query lane.
 """
 from __future__ import annotations
 
@@ -35,132 +45,178 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .ref import gather_rows
+
 INVALID = -1
+LANES = 128
+WORD_BITS = 32
 
 
-def _neighbor_expand_kernel(*refs, strategy: str, m: int, n: int, n_l: int,
-                            cap: int, t: int, has_mask: bool, has_vis: bool):
+def bitmap_words(n: int) -> int:
+    """Words per lane of a packed n-id bitmap: a whole number of 128-lane
+    rows holding 32 ids each."""
+    return -(-n // (WORD_BITS * LANES)) * LANES
+
+
+def pack_bitmap(mask):
+    """(B, n) bool -> (B, W / 128, 128) int32 with W = bitmap_words(n).
+
+    Id ``i`` is bit ``i // W`` of word ``i % W`` (zero-padded past n), so
+    each bit plane is a contiguous slice of the mask and packing is one
+    elementwise fusion, with no (B, n) word array in between."""
+    b, n = mask.shape
+    w = bitmap_words(n)
+    padded = jnp.pad(mask, ((0, 0), (0, WORD_BITS * w - n)))
+    planes = padded.reshape(b, WORD_BITS, w)
+    packed = functools.reduce(jnp.bitwise_or, (
+        planes[:, k].astype(jnp.uint32) << k for k in range(WORD_BITS)))
+    return jax.lax.bitcast_convert_type(packed, jnp.int32).reshape(
+        b, w // LANES, LANES)
+
+
+def _neighbor_expand_kernel(*refs, strategy: str, m: int, n: int, cap: int,
+                            t: int, has_mask: bool, has_vis: bool):
     """One query lane.  Ref layout (built by the wrapper, in order):
 
     head_ref (1, H) SMEM       candidates scanned first
     exp_ids_ref (1, t) SMEM    tail ids to 2-hop expand   [compress/two_hop]
-    exp_rows_ref (1, t) SMEM   their rows in the table    [compress/two_hop]
-    pm_ref (1, n) VMEM         predicate bitmap           [has_mask]
-    vis_ref (1, n) VMEM        visited bitmap             [has_vis]
-    tbl_ref (n_l, cap) ANY     level neighbor table       [compress/two_hop]
-    o_ref (1, m) VMEM          packed output ids
+    hop2_ref (t, cap) SMEM     their rows (-1 if absent)  [compress/two_hop]
+    pm_ref (R, 128) VMEM       packed predicate bitmap    [has_mask]
+    vis_ref (R, 128) VMEM      packed visited bitmap      [has_vis]
+    o_ref (1, m) SMEM          packed output ids
+    vis_out_ref (R, 128) VMEM  visited | packed ids       [has_vis]
     cnt_ref (1,) SMEM scratch  number packed so far
-    block_ref (t, cap) VMEM    DMA-landed 2-hop rows      [compress/two_hop]
-    sems (2,) DMA semaphores                              [compress/two_hop]
+    seen_ref (R, 128) VMEM     visited | packed bitmap    [compress/two_hop,
+                               scratch unless has_vis: then vis_out_ref]
     """
     refs = list(refs)
     head_ref = refs.pop(0)
     has_exp = strategy != "filter"
     exp_ids_ref = refs.pop(0) if has_exp else None
-    exp_rows_ref = refs.pop(0) if has_exp else None
+    hop2_ref = refs.pop(0) if has_exp else None
     pm_ref = refs.pop(0) if has_mask else None
     vis_ref = refs.pop(0) if has_vis else None
-    tbl_ref = refs.pop(0) if has_exp else None
     o_ref = refs.pop(0)
+    vis_out_ref = refs.pop(0) if has_vis else None
     cnt_ref = refs.pop(0)
-    block_ref = refs.pop(0) if has_exp else None
-    sems = refs.pop(0) if has_exp else None
+    seen_ref = None
+    if has_exp:
+        seen_ref = vis_out_ref if has_vis else refs.pop(0)
+    # where a packed id is recorded: the seen set, or for 'filter' the
+    # visited output alone (its checks read the input visited bitmap)
+    record_ref = seen_ref if has_exp else vis_out_ref
 
-    o_ref[...] = jnp.full((1, m), INVALID, jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+
+    words = bitmap_words(n)
+
+    def locate(cid):
+        """(row, lane, bit) of id ``cid`` in a packed bitmap."""
+        w = cid % words
+        return w // LANES, w % LANES, cid // words
+
+    def bit_set(ref, cid):
+        r, col, bit = locate(cid)
+        row = ref[pl.ds(r, 1), :]
+        word = jnp.sum(jnp.where(lane == col, row, 0))
+        return (jax.lax.shift_right_logical(word, bit) & 1) == 1
+
+    def mark(ref, cid):
+        r, col, bit = locate(cid)
+        ref[pl.ds(r, 1), :] = ref[pl.ds(r, 1), :] | jnp.where(
+            lane == col, jax.lax.shift_left(jnp.int32(1), bit), 0)
+
+    for j in range(m):
+        o_ref[0, j] = INVALID
     cnt_ref[0] = 0
+    if has_vis:
+        vis_out_ref[...] = vis_ref[...]
+    elif has_exp:
+        seen_ref[...] = jnp.zeros(seen_ref.shape, jnp.int32)
 
     def try_pack(cid):
-        """First-occurrence pack: the output tile doubles as the seen-set."""
+        """First-occurrence pack: a packed id joins the seen set."""
         cnt = cnt_ref[0]
         safe = jnp.clip(cid, 0, n - 1)
-        ok = (cid >= 0) & (cnt < m)
+        ok = cid >= 0
         if has_mask:
-            ok &= pm_ref[0, safe]
-        if has_vis:
-            ok &= jnp.logical_not(vis_ref[0, safe])
-        if has_exp:  # 'filter' scans a duplicate-free stored row: no dedup
-            ok &= jnp.logical_not(jnp.any(o_ref[0, :] == cid))
+            ok &= bit_set(pm_ref, safe)
+        if has_exp:
+            ok &= jnp.logical_not(bit_set(seen_ref, safe))
+        elif has_vis:  # 'filter' scans a duplicate-free stored row
+            ok &= jnp.logical_not(bit_set(vis_ref, safe))
 
         @pl.when(ok)
         def _():
             o_ref[0, cnt] = cid
             cnt_ref[0] = cnt + 1
+            if record_ref is not None:
+                mark(record_ref, safe)
 
-    # ---- 2-hop row DMAs, depth-2 pipelined (absent rows land row 0 of the
-    # table and are masked off at scan time via exp_rows < 0) ----
-    if has_exp:
-        def start(tt):
-            r = jnp.clip(exp_rows_ref[0, tt], 0, n_l - 1)
-            pltpu.make_async_copy(tbl_ref.at[pl.ds(r, 1)],
-                                  block_ref.at[pl.ds(tt, 1)],
-                                  sems.at[jax.lax.rem(tt, 2)]).start()
+    def scan(total, candidate):
+        """try_pack over candidate(0..total-1), until m ids are packed."""
+        def cond(carry):
+            s, cnt = carry
+            return (s < total) & (cnt < m)
 
-        start(0)
-        if t > 1:
-            start(1)
+        def body(carry):
+            s, _ = carry
+            try_pack(candidate(s))
+            return s + 1, cnt_ref[0]
 
-        def dma_body(tt, _):
-            r = jnp.clip(exp_rows_ref[0, tt], 0, n_l - 1)
-            pltpu.make_async_copy(tbl_ref.at[pl.ds(r, 1)],
-                                  block_ref.at[pl.ds(tt, 1)],
-                                  sems.at[jax.lax.rem(tt, 2)]).wait()
-
-            @pl.when(tt + 2 < t)
-            def _():
-                start(tt + 2)
-
-            return 0
-
-        jax.lax.fori_loop(0, t, dma_body, 0)
+        jax.lax.while_loop(cond, body, (jnp.int32(0), cnt_ref[0]))
 
     # ---- phase 1: head candidates in stored order ----
-    h = head_ref.shape[1]
-
-    def head_body(j, _):
-        try_pack(head_ref[0, j])
-        return 0
-
-    jax.lax.fori_loop(0, h, head_body, 0)
+    scan(head_ref.shape[1], lambda j: head_ref[0, j])
 
     # ---- phase 2: the 2-hop stream, in the strategy's scan order ----
     if not has_exp:
         return
     if strategy == "compress":
-        # per tail t: the tail id itself, then its row left-to-right
-        total = t * (cap + 1)
-
-        def scan_body(s, _):
+        # per tail: the tail id itself, then its row left-to-right
+        def candidate(s):
             tt = s // (cap + 1)
             r = s % (cap + 1)
-            present = exp_rows_ref[0, tt] >= 0
-            hid = block_ref[tt, jnp.clip(r - 1, 0, cap - 1)]
-            cid = jnp.where(r == 0, exp_ids_ref[0, tt],
-                            jnp.where(present, hid, INVALID))
-            try_pack(cid)
-            return 0
+            hid = hop2_ref[tt, jnp.maximum(r - 1, 0)]
+            return jnp.where(r == 0, exp_ids_ref[0, tt], hid)
+
+        scan(t * (cap + 1), candidate)
     else:  # two_hop: j-th neighbor of every 1-hop node before the (j+1)-th
-        total = t * cap
-
-        def scan_body(s, _):
-            tt = jax.lax.rem(s, t)
-            j = s // t
-            present = exp_rows_ref[0, tt] >= 0
-            cid = jnp.where(present, block_ref[tt, j], INVALID)
-            try_pack(cid)
-            return 0
-
-    jax.lax.fori_loop(0, total, scan_body, 0)
+        scan(t * cap, lambda s: hop2_ref[s % t, s // t])
 
 
 @functools.partial(jax.jit,
                    static_argnames=("strategy", "m", "m_beta", "interpret"))
 def neighbor_expand_pallas(row, nbr_table, pos, pass_mask=None, visited=None,
                            *, strategy: str, m: int, m_beta: int = 0,
-                           interpret: bool = True):
+                           interpret: bool = False):
     """row (B, cap), nbr_table (n_l, cap), pos (n,) -> (B, m) int32 ids.
 
-    Bit-identical to :func:`repro.kernels.neighbor_expand.ref.
-    neighbor_expand_ref` (enforced by tests/test_neighbor_expand.py).
+    pass_mask / visited are (B, n) bool, their :func:`pack_bitmap` words,
+    or None.  Bit-identical to
+    :func:`repro.kernels.neighbor_expand.ref.neighbor_expand_ref`
+    (enforced by tests/test_neighbor_expand.py).
+    """
+    def pack(a):
+        return a if a is None or a.dtype == jnp.int32 else pack_bitmap(a)
+
+    ids, _ = neighbor_expand_packed(
+        row, nbr_table, pos, pack(pass_mask), pack(visited),
+        strategy=strategy, m=m, m_beta=m_beta, interpret=interpret)
+    return ids
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("strategy", "m", "m_beta", "interpret"))
+def neighbor_expand_packed(row, nbr_table, pos, pass_words=None,
+                           visited_words=None, *, strategy: str, m: int,
+                           m_beta: int = 0, interpret: bool = False):
+    """:func:`neighbor_expand_pallas` over bitmaps already packed by
+    :func:`pack_bitmap` ((B, n / 4096, 128) int32, or None).
+
+    Returns ``(ids, visited_words | ids)``: the visited bitmap with every
+    returned id set, written over ``visited_words`` (None when it is
+    None) — the level-0 beam's visited update, with no (B, n) pass.
     """
     b, cap = row.shape
     n = pos.shape[0]
@@ -175,48 +231,56 @@ def neighbor_expand_pallas(row, nbr_table, pos, pass_mask=None, visited=None,
     if head.shape[1] == 0:   # zero-width SMEM blocks are illegal; a single
         head = jnp.full((b, 1), INVALID, jnp.int32)   # -1 never packs
     has_exp = exp is not None
-    has_mask = pass_mask is not None
-    has_vis = visited is not None
+    has_mask = pass_words is not None
+    has_vis = visited_words is not None
 
-    inputs = [head]
-    in_specs = [pl.BlockSpec((1, head.shape[1]), lambda i: (i, 0),
-                             memory_space=pltpu.SMEM)]
+    def lane_block(a, memory_space=None):
+        """Per-lane block whose last two dims are the whole array's."""
+        kw = {} if memory_space is None else dict(memory_space=memory_space)
+        return pl.BlockSpec((None,) + a.shape[1:],
+                            lambda i: (i,) + (0,) * (a.ndim - 1), **kw)
+
+    inputs = [head[:, None, :]]
     t = 1
-    tbl = nbr_table
+    tbl_cap = nbr_table.shape[1]
     if has_exp:
         if exp.shape[1] == 0:   # m_beta == cap: dummy -1 tail, never packs
             exp = jnp.full((b, 1), INVALID, jnp.int32)
         t = exp.shape[1]
-        exp_rows = jnp.where(exp >= 0, pos[jnp.clip(exp, 0, n - 1)], INVALID)
-        if tbl.shape[0] == 0:   # empty level: every 2-hop row is absent
-            tbl = jnp.full((1, cap), INVALID, jnp.int32)
-        inputs += [exp, exp_rows]
-        in_specs += [
-            pl.BlockSpec((1, t), lambda i: (i, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, t), lambda i: (i, 0), memory_space=pltpu.SMEM),
-        ]
+        inputs += [exp[:, None, :], gather_rows(nbr_table, pos, exp)]
+    smem_inputs = len(inputs)
     if has_mask:
-        inputs.append(pass_mask)
-        in_specs.append(pl.BlockSpec((1, n), lambda i: (i, 0)))
+        inputs.append(pass_words)
     if has_vis:
-        inputs.append(visited)
-        in_specs.append(pl.BlockSpec((1, n), lambda i: (i, 0)))
+        inputs.append(visited_words)
+    in_specs = [lane_block(a, pltpu.SMEM) for a in inputs[:smem_inputs]]
+    in_specs += [lane_block(a) for a in inputs[smem_inputs:]]
+    ids_shape = jax.ShapeDtypeStruct((b, 1, m), jnp.int32)
+    out_shape = [ids_shape]
+    out_specs = [pl.BlockSpec((None, 1, m), lambda i: (i, 0, 0),
+                              memory_space=pltpu.SMEM)]
+    aliases = {}
+    if has_vis:
+        out_shape.append(jax.ShapeDtypeStruct(visited_words.shape,
+                                              jnp.int32))
+        out_specs.append(lane_block(visited_words))
+        aliases = {len(inputs) - 1: 1}
     scratch = [pltpu.SMEM((1,), jnp.int32)]
-    if has_exp:
-        inputs.append(tbl)
-        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-        scratch += [pltpu.VMEM((t, cap), jnp.int32),
-                    pltpu.SemaphoreType.DMA((2,))]
+    if has_exp and not has_vis:
+        scratch.append(pltpu.VMEM((bitmap_words(n) // LANES, LANES),
+                                  jnp.int32))
 
     kern = functools.partial(
-        _neighbor_expand_kernel, strategy=strategy, m=m, n=n,
-        n_l=tbl.shape[0], cap=cap, t=t, has_mask=has_mask, has_vis=has_vis)
-    return pl.pallas_call(
+        _neighbor_expand_kernel, strategy=strategy, m=m, n=n, cap=tbl_cap,
+        t=t, has_mask=has_mask, has_vis=has_vis)
+    outs = pl.pallas_call(
         kern,
         grid=(b,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, m), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, m), jnp.int32),
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=scratch,
+        input_output_aliases=aliases,
         interpret=interpret,
     )(*inputs)
+    return outs[0][:, 0, :], (outs[1] if has_vis else None)

@@ -17,13 +17,13 @@ INVALID = -1
 
 def neighbor_expand(row, nbr_table, pos, pass_mask=None, visited=None, *,
                     strategy: str, m: int, m_beta: int = 0,
-                    use_kernel: bool = False, interpret: bool = True):
+                    use_kernel: bool = False, interpret: bool = False):
     """Up-to-m expansion ids per lane, in candidate order, -1 padded.
 
     row (B, cap) int32 1-hop neighbor ids (-1 padded); nbr_table (n_l, cap)
     the level's neighbor table; pos (n,) global id -> level row (or -1);
     pass_mask / visited (B, n) bool or None (None = all pass / none
-    visited).  strategy in {'filter', 'compress', 'two_hop'} (Figure 4);
+    visited); the kernel path also takes them as ``pack_bitmap`` words.  strategy in {'filter', 'compress', 'two_hop'} (Figure 4);
     ``m_beta`` is the compressed head width (compress only).
     """
     if strategy not in ("filter", "compress", "two_hop"):

@@ -53,7 +53,7 @@ def use_scatter_dedup(n: int, c: int) -> bool:
     return n <= SCATTER_DEDUP_FACTOR * c * math.log2(max(c, 2))
 
 
-def _gather_rows(nbr_table: Array, pos: Array, gids: Array) -> Array:
+def gather_rows(nbr_table: Array, pos: Array, gids: Array) -> Array:
     """Neighbor rows for global ids: (..., ) -> (..., cap).
 
     Raw-array twin of ``repro.core.graph.neighbor_rows``: ids absent from
@@ -77,11 +77,11 @@ def expansion_candidates(row: Array, nbr_table: Array, pos: Array,
         return row
     if strategy == "compress":
         head, tail = row[:, :m_beta], row[:, m_beta:]
-        hop2 = _gather_rows(nbr_table, pos, tail)          # (B, T, cap)
+        hop2 = gather_rows(nbr_table, pos, tail)          # (B, T, cap)
         two = jnp.concatenate([tail[..., None], hop2], axis=2)
         return jnp.concatenate([head, two.reshape(b, -1)], axis=1)
     if strategy == "two_hop":
-        hop2 = _gather_rows(nbr_table, pos, row)           # (B, cap, cap)
+        hop2 = gather_rows(nbr_table, pos, row)           # (B, cap, cap)
         inter = jnp.transpose(hop2, (0, 2, 1)).reshape(b, -1)
         return jnp.concatenate([row, inter], axis=1)
     raise ValueError(strategy)

@@ -43,7 +43,7 @@ def _pna_kernel(adj_ref, feat_ref, o_ref, *, n: int, f: int):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def pna_aggregate_pallas(adj, feats, interpret: bool = True):
+def pna_aggregate_pallas(adj, feats, interpret: bool = False):
     """adj (B, N, N) f32 in {0,1}, feats (B, N, F) -> (B, N, 4F)."""
     b, n, _ = adj.shape
     f = feats.shape[-1]

@@ -11,7 +11,7 @@ from .ref import pna_aggregate_ref, pna_aggregate_segment_ref
 
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
 def pna_aggregate(adj, feats, use_kernel: bool = True,
-                  interpret: bool = True):
+                  interpret: bool = False):
     """Dense-batched PNA aggregation: (B,N,N), (B,N,F) -> (B,N,4F)."""
     if not use_kernel:
         return pna_aggregate_ref(adj, feats)

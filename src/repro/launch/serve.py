@@ -18,6 +18,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import configure_compile_cache
 from repro.core import AcornConfig, SearchRequest, recall_at_k
 from repro.data import make_hcps_dataset, make_lcps_dataset, make_workload
 from repro.serve import (EngineConfig, RuntimeConfig, ServingEngine,
@@ -123,6 +124,7 @@ def main():
     ap.add_argument("--ef-ladder", default="",
                     help="comma-separated ef ladder for SLO routing")
     args = ap.parse_args()
+    configure_compile_cache()
 
     if args.workload == "equals":
         ds = make_lcps_dataset(n=args.n, d=args.d, seed=0)

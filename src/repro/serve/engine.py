@@ -27,8 +27,11 @@ Operational wrapper around HybridIndex for production serving:
         (``repro.distributed.collectives.gathered_topk_merge``);
       - **host loop (:meth:`search_batch_host`):** the original Python
         walk over shards with a host-side merge — retained as the parity
-        oracle for the SPMD path and as the automatic fallback when the
-        host has fewer devices than corpus shards.
+        oracle for the SPMD path, for single-shard engines, and, in auto
+        mode only, for hosts with fewer devices than corpus shards.  An
+        explicit ``corpus_parallel`` that does not fit raises at
+        construction.  ``stats["spmd_batches"]`` /
+        ``stats["host_loop_batches"]`` count which path served.
 
     Both paths are bit-identical (gated in tests/test_corpus_parallel.py);
   * execution policy as ONE value — ``EngineConfig.spec``
@@ -70,6 +73,7 @@ from repro.core.predicates import AttributeTable
 from repro.distributed.collectives import merge_topk  # noqa: F401  (re-export)
 from repro.distributed.corpus_parallel import (ShardedCorpus,
                                                corpus_search_batch,
+                                               place_corpus,
                                                resolve_corpus_mesh_shape,
                                                stack_corpus, stack_regex_aux)
 
@@ -121,6 +125,7 @@ class ServingEngine:
                  cfg: EngineConfig, seed: int = 0):
         self.cfg = cfg
         self.acorn = acorn
+        self.spmd_mesh_shape()  # an explicit mesh that does not fit raises
         n = x.shape[0]
         per = (n + cfg.n_shards - 1) // cfg.n_shards
         self.shards: List[_Shard] = []
@@ -135,11 +140,14 @@ class ServingEngine:
         self.stats: Dict[str, float] = {"queries": 0, "batches": 0,
                                         "prefilter_routed": 0,
                                         "graph_routed": 0,
-                                        "duplicated_dispatches": 0}
+                                        "duplicated_dispatches": 0,
+                                        "spmd_batches": 0,
+                                        "host_loop_batches": 0}
         # SPMD state: stacked corpus (rebuilt lazily after rebuild_shard),
         # per-regex-leaf-set aux bitmaps, and the compiled-variant cache
         # for the mesh kernels
         self._corpus: Optional[ShardedCorpus] = None
+        self._corpus_mesh: Optional[Tuple[int, int]] = None
         self._aux_cache: Dict[tuple, "jnp.ndarray"] = {}
         self.spmd_cache = VariantCache()
 
@@ -156,7 +164,9 @@ class ServingEngine:
 
     def spmd_mesh_shape(self) -> Optional[Tuple[int, int]]:
         """The ``(data, corpus)`` mesh the SPMD path would run on, or
-        ``None`` when this engine serves through the host loop."""
+        ``None`` when this engine serves through the host loop.  Raises
+        when ``corpus_parallel`` is set explicitly and the host cannot
+        fit the mesh."""
         if self.cfg.host_fallback:
             return None
         spec = self.execution_spec()
@@ -164,13 +174,21 @@ class ServingEngine:
             self.cfg.n_shards, data_parallel=spec.data_parallel,
             corpus_parallel=spec.corpus_parallel)
 
-    def _stacked_corpus(self) -> ShardedCorpus:
-        if self._corpus is None:
-            self._corpus = stack_corpus(
+    def sharded_corpus(self) -> ShardedCorpus:
+        """The stacked corpus as the SPMD path holds it: one shard per
+        corpus device of :meth:`spmd_mesh_shape`, which must resolve
+        (restacked after ``rebuild_shard``)."""
+        shape = self.spmd_mesh_shape()
+        if shape is None:
+            raise ValueError("this engine serves through the host loop; "
+                             "it holds no sharded corpus")
+        if self._corpus is None or self._corpus_mesh != shape:
+            self._corpus = place_corpus(stack_corpus(
                 [s.index.graph for s in self.shards],
                 [s.index.x for s in self.shards],
                 [s.base for s in self.shards],
-                tables=[s.index.table for s in self.shards])
+                tables=[s.index.table for s in self.shards]), *shape)
+            self._corpus_mesh = shape
         return self._corpus
 
     def compile(self, predicates: Sequence[Predicate]) -> PredicateProgram:
@@ -193,7 +211,8 @@ class ServingEngine:
     def search_batch(self, request: Union[SearchRequest, "jnp.ndarray"],
                      predicates: Optional[Predicates] = None):
         """One batched step across all shards + merge (SPMD when the mesh
-        fits, host loop otherwise — bit-identical either way).
+        resolves, host loop otherwise — bit-identical either way; the
+        ``spmd_batches`` / ``host_loop_batches`` stats count which).
 
         Accepts a :class:`SearchRequest` (whose ``k``/``ef``/``route``
         override the engine defaults for this call) or the legacy
@@ -258,7 +277,7 @@ class ServingEngine:
         k = cfg.k if k is None else k
         ef = (ef or cfg.ef) or acorn.ef_search
         n_shards = cfg.n_shards
-        corpus = self._stacked_corpus()
+        corpus = self.sharded_corpus()
         n_max = corpus.x.shape[1]
 
         program = self._program(preds, b)
@@ -305,6 +324,7 @@ class ServingEngine:
 
         self.stats["queries"] += b
         self.stats["batches"] += 1
+        self.stats["spmd_batches"] += 1
         if not alive.any():
             # every shard (and mirror) down: degrade to an empty result set
             return sentinel_result(b, k)
@@ -401,6 +421,7 @@ class ServingEngine:
                 (result.routes == "graph").sum())
         self.stats["queries"] += b
         self.stats["batches"] += 1
+        self.stats["host_loop_batches"] += 1
         if not all_ids:
             # every shard (and mirror) down: degrade to an empty result set
             # instead of crashing the serving path — availability first

@@ -27,3 +27,16 @@ except ImportError:
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture
+def interpret_kernels():
+    """Trace the test's Pallas kernels in TPU interpret mode.
+
+    The program compiles its kernels by default, and the CPU has no Mosaic
+    backend.  Tests that call a kernel wrapper pass ``interpret=True``
+    themselves; this fixture is for code paths that expose no such knob
+    (the model stack's ``pna_aggregate`` call)."""
+    from jax.experimental.pallas import tpu as pltpu
+    with pltpu.force_tpu_interpret_mode():
+        yield

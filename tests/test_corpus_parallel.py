@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import AcornConfig, hybrid_search
+from repro.core import AcornConfig, ExecutionSpec, hybrid_search
 from repro.core.predicates import evaluate_batch
 from repro.data import make_lcps_dataset, make_workload
 from repro.distributed import (resolve_corpus_mesh_shape, shard_slice,
@@ -36,8 +36,10 @@ def test_resolve_corpus_mesh_shape():
     assert resolve_corpus_mesh_shape(1) is None
     # explicit single-shard request: SPMD with all devices on 'data'
     assert resolve_corpus_mesh_shape(1, corpus_parallel=1) == (ndev, 1)
-    # more shards than devices: host fallback
+    # more shards than devices: host fallback in auto mode only
     assert resolve_corpus_mesh_shape(ndev + 1) is None
+    with pytest.raises(ValueError, match="only .* devices are local"):
+        resolve_corpus_mesh_shape(ndev + 1, corpus_parallel=ndev + 1)
     # the corpus axis holds one shard per device — mismatches are errors
     with pytest.raises(ValueError):
         resolve_corpus_mesh_shape(2, corpus_parallel=3)
@@ -61,6 +63,22 @@ def test_engine_falls_back_without_devices():
     ids, d = eng.serve(wl.xq, wl.predicates)
     assert ids.shape == (5, 5)
     assert eng.spmd_traces() == {}  # nothing ran through the mesh
+    # ... and the stats say which path served
+    assert eng.stats["host_loop_batches"] == eng.stats["batches"] == 1
+    assert eng.stats["spmd_batches"] == 0
+
+
+def test_engine_raises_when_explicit_mesh_does_not_fit():
+    """Asking for SPMD explicitly never quietly degrades to the host loop:
+    the engine refuses before it builds any shard."""
+    ndev = jax.local_device_count()
+    ds = make_lcps_dataset(n=400, d=8, card=4, seed=0)
+    acorn = AcornConfig(M=8, gamma=4, m_beta=16, ef_search=16, buckets=(8,))
+    spec = ExecutionSpec(corpus_parallel=ndev + 1)
+    with pytest.raises(ValueError, match="only .* devices are local"):
+        ServingEngine(ds.x, ds.table, acorn,
+                      EngineConfig(batch_size=8, k=5, n_shards=ndev + 1,
+                                   spec=spec))
 
 
 def test_stack_corpus_padding_is_search_invisible():

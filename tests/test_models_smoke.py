@@ -153,7 +153,7 @@ def test_pna_neighbor_sampler_real():
     assert np.isfinite(np.asarray(logits)).all()
 
 
-def test_pna_dense_kernel_path_matches_ref():
+def test_pna_dense_kernel_path_matches_ref(interpret_kernels):
     from repro.models.gnn import forward_dense
     arch = get_arch("pna")
     cfg = arch.config(reduced=True, shape="molecule")

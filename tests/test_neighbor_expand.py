@@ -24,6 +24,8 @@ from repro.data import make_lcps_dataset
 from repro.kernels.neighbor_expand import (neighbor_expand,
                                            neighbor_expand_argsort,
                                            neighbor_expand_ref)
+from repro.kernels.neighbor_expand.kernel import (neighbor_expand_packed,
+                                                  pack_bitmap)
 
 KEY = jax.random.PRNGKey(0)
 STRATEGIES = ["filter", "compress", "two_hop"]
@@ -113,6 +115,23 @@ def test_parity_m_wider_than_candidates(strategy):
     """m larger than the whole candidate stream: all survivors + -1 pad."""
     case = make_case(seed=11, cap=4, n=60, n_l=40)
     assert_all_equal(*case, strategy=strategy, m=64, m_beta=2)
+
+
+@pytest.mark.parametrize("strategy", STRATEGIES)
+def test_packed_entry_point_updates_visited(strategy):
+    """neighbor_expand_packed returns the same ids from packed bitmaps and
+    the visited bitmap with exactly those ids set (the beam's update)."""
+    row, tbl, pos, pm, vis = make_case(seed=11, dup_heavy=True)
+    want = neighbor_expand_ref(row, tbl, pos, pm, vis, strategy=strategy,
+                               m=8, m_beta=4)
+    ids, vis_out = neighbor_expand_packed(
+        row, tbl, pos, pack_bitmap(pm), pack_bitmap(vis), strategy=strategy,
+        m=8, m_beta=4, interpret=True)
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(want))
+    safe = jnp.clip(want, 0, vis.shape[1] - 1)
+    vis_want = vis.at[jnp.arange(vis.shape[0])[:, None], safe].max(want >= 0)
+    np.testing.assert_array_equal(np.asarray(vis_out),
+                                  np.asarray(pack_bitmap(vis_want)))
 
 
 def test_first_occurrence_keeps_scan_order():
@@ -222,7 +241,8 @@ def test_hybrid_search_expand_kernel_knob(graph_ds):
     from repro.core import ExecutionSpec
     ids0, d0, st0 = hybrid_search(g, ds.x, xq, masks, **kw)
     ids1, d1, st1 = hybrid_search(g, ds.x, xq, masks,
-                                  spec=ExecutionSpec(expand_kernel=True),
+                                  spec=ExecutionSpec(expand_kernel=True,
+                                                     interpret=True),
                                   **kw)
     np.testing.assert_array_equal(np.asarray(ids0), np.asarray(ids1))
     np.testing.assert_array_equal(np.asarray(d0), np.asarray(d1))

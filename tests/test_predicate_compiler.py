@@ -328,7 +328,7 @@ def test_search_batch_legacy_kwargs_raise_and_keys_on_spec(golden_cell):
     (key,) = cache.fns
     spec = key[-1]
     assert isinstance(spec, ExecutionSpec)
-    assert spec == ExecutionSpec(use_kernel=False, interpret=True,
+    assert spec == ExecutionSpec(use_kernel=False, interpret=False,
                                  expand_kernel=False, data_parallel=1,
                                  corpus_parallel=1)
     # every retired kwarg is named in the error, sorted, with its hint
@@ -525,7 +525,7 @@ def test_execution_spec_resolution_semantics():
     s = ExecutionSpec(use_kernel=True)
     assert s.expand_kernel is None and s.resolved_expand_kernel() is True
     r = s.resolve(data_parallel=4, corpus_parallel=2)
-    assert r == ExecutionSpec(use_kernel=True, interpret=True,
+    assert r == ExecutionSpec(use_kernel=True, interpret=False,
                               expand_kernel=True, data_parallel=4,
                               corpus_parallel=2)
     assert hash(r) == hash(r)  # usable as a dict key
